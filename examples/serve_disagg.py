@@ -39,8 +39,7 @@ def main() -> None:
 
     eng = ServeEngine(cfg, params,
                       PoolConfig(num_pages=64, page_tokens=8,
-                                 max_pages_per_seq=8),
-                      backend="ref")
+                                 max_pages_per_seq=8))
 
     rng = np.random.default_rng(0)
     rids = [eng.submit(list(rng.integers(1, cfg.vocab_size, size=6)),
@@ -57,8 +56,7 @@ def main() -> None:
     pod0 = PagedKVPool(orch, cfg, pc, owner_pid=1)
     pod1 = PagedKVPool(orch, cfg, pc, owner_pid=2)
     pages = [5, 6]
-    moved = transfer_pages_cross_pod(pod0, pod1, pages, [10, 11],
-                                     backend="ref")
+    moved = transfer_pages_cross_pod(pod0, pod1, pages, [10, 11])
     print(f"cross-pod fallback for {len(pages)} pages: {moved:,} bytes "
           f"copied vs {8*len(pages)} pointer bytes in-pod "
           f"({moved/(8*len(pages)):,.0f}× more traffic)")

@@ -3,7 +3,7 @@
 Each kernel ships three files:
   kernel.py — ``pl.pallas_call`` body with explicit BlockSpec VMEM tiling
   ops.py    — jit'd dispatching wrapper (pallas on TPU, interpret for
-              tests, pure-jnp reference on CPU dry-runs)
+              tests, pure-jnp reference on the CPU — ``default_backend``)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
 Kernels:
@@ -18,3 +18,18 @@ Kernels:
   scope_copy      — page gather/scatter between pool and contiguous
                     buffers (fallback transport / memcpy baseline).
 """
+
+import jax
+
+
+def default_backend() -> str:
+    """Backend for a kernel call that names none: the compiled kernel on
+    a TPU, the pure-jnp reference on the CPU. A failed device query
+    raises, and so does any other platform — no path falls back to the
+    reference by swallowing an error."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return "tpu"
+    if platform == "cpu":
+        return "ref"
+    raise RuntimeError(f"no kernel backend for platform {platform!r}")

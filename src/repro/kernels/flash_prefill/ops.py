@@ -7,15 +7,9 @@ from typing import Optional
 
 import jax
 
+from .. import default_backend
 from .kernel import flash_prefill_pallas
 from .ref import flash_prefill_ref
-
-
-def _default_backend() -> str:
-    try:
-        return "tpu" if jax.devices()[0].platform == "tpu" else "ref"
-    except Exception:  # pragma: no cover
-        return "ref"
 
 
 @partial(jax.jit,
@@ -23,7 +17,7 @@ def _default_backend() -> str:
 def flash_prefill(q, k, v, window: int = 0, softcap: float = 0.0,
                   bq: int = 512, bk: int = 512,
                   backend: Optional[str] = None):
-    backend = backend or _default_backend()
+    backend = backend or default_backend()
     if backend == "ref":
         return flash_prefill_ref(q, k, v, window=window, softcap=softcap)
     return flash_prefill_pallas(q, k, v, window=window, softcap=softcap,

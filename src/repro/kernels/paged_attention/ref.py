@@ -11,14 +11,15 @@ PERM_SEALED = 1
 
 
 def paged_attention_ref(q, k_pool, v_pool, block_tab, seq_lens, perm_bits,
-                        sandbox, bitmap):
+                        sandbox, bitmap, layer: int = 0):
     """Same contract as the kernel. All math in fp32.
 
-    q: (B, Hq, D); pools: (P, T, Hkv, D); block_tab: (B, MAXP).
+    q: (B, Hq, D); pools: (L, P, Hkv, T, D); block_tab: (B, MAXP).
     Returns (out (B, Hq, D), oob (B,) i32).
     """
+    k_pool, v_pool = k_pool[layer], v_pool[layer]
     B, Hq, D = q.shape
-    P, T, Hkv, _ = k_pool.shape
+    P, Hkv, T, _ = k_pool.shape
     MAXP = block_tab.shape[1]
     qpk = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
@@ -36,9 +37,9 @@ def paged_attention_ref(q, k_pool, v_pool, block_tab, seq_lens, perm_bits,
     valid_page = in_use & jnp.where(sb_on > 0, ok, in_bounds)
     oob = jnp.sum(in_use & ~valid_page, axis=1).astype(jnp.int32)
 
-    # gather pages: (B, MAXP, T, Hkv, D)
-    k = k_pool[clamped].astype(jnp.float32)
-    v = v_pool[clamped].astype(jnp.float32)
+    # gather pages: (B, MAXP, Hkv, T, D) → (B, MAXP·T, Hkv, D)
+    k = k_pool[clamped].astype(jnp.float32).transpose(0, 1, 3, 2, 4)
+    v = v_pool[clamped].astype(jnp.float32).transpose(0, 1, 3, 2, 4)
     k = k.reshape(B, MAXP * T, Hkv, D)
     v = v.reshape(B, MAXP * T, Hkv, D)
 

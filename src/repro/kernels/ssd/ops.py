@@ -10,14 +10,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import default_backend
 from .kernel import ssd_intra_chunk_pallas
-
-
-def _default_backend() -> str:
-    try:
-        return "tpu" if jax.devices()[0].platform == "tpu" else "ref"
-    except Exception:  # pragma: no cover
-        return "ref"
 
 
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None,
@@ -26,7 +20,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None,
 
     Returns (y (B,S,H,P), final_state (B,H,N,P)).
     """
-    backend = backend or _default_backend()
+    backend = backend or default_backend()
     if backend == "ref":
         from .ref import ssd_chunked_ref
 

@@ -256,8 +256,7 @@ def _compile_metrics(cfg, shape, mesh, rules, variant: str = "",
             if val is not None:
                 rec[f] = int(val)
 
-    from repro.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     if verbose:
         print({k: val for k, val in (cost or {}).items()
                if k in ("flops", "bytes accessed")})
@@ -420,8 +419,11 @@ def main() -> int:
                 cmd += ["--tag", args.tag]
             if args.costing:
                 cmd += ["--costing"]
+            # the cell compiles on 512 host placeholder devices: pin the
+            # child to the CPU so it never reaches for an attached chip
             p = subprocess.Popen(cmd, stdout=subprocess.DEVNULL,
-                                 stderr=subprocess.DEVNULL)
+                                 stderr=subprocess.DEVNULL,
+                                 env={**os.environ, "JAX_PLATFORMS": "cpu"})
             running.append((p, arch, shape_name, mesh_kind, time.time()))
         for item in list(running):
             p, arch, shape_name, mesh_kind, t0 = item

@@ -25,7 +25,7 @@ def main() -> None:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from repro.sharding import shard_map
     from repro.configs import get_config
     from repro.launch.dryrun import collective_bytes
     from repro.launch.mesh import make_production_mesh

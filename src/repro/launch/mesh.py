@@ -19,21 +19,10 @@ Axis roles:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model: int = 1, data: int = 1, pod: int = 1):
-    """Small mesh over however many (host) devices exist — used by tests
-    that run with XLA_FLAGS=--xla_force_host_platform_device_count=N."""
-    n = len(jax.devices())
-    want = model * data * pod
-    if want > n:
-        raise ValueError(f"need {want} devices, have {n}")
-    if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -7,16 +7,37 @@ busy-wait scheduling (§5.8).
 
 CPU-runnable:  PYTHONPATH=src python -m repro.launch.serve \
                    --requests 16 --max-new 12
+
+The kernels take their default backend: the compiled Pallas kernels on a
+TPU, the jnp reference on the CPU.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
 
 import jax
 import numpy as np
+
+REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is the cache (JAX reads it
+    itself) and nothing here overrides it. Otherwise the cache is the
+    fixed ``<repo>/.jax_cache``: the path is part of the cache key, so a
+    directory that moved between runs would never hit."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main() -> None:
@@ -31,6 +52,7 @@ def main() -> None:
                     help="fixed busy-wait sleep (default: §5.8 adaptive)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.models import build_model
     from repro.serving import PoolConfig, ServeEngine
@@ -48,8 +70,7 @@ def main() -> None:
         cfg, params,
         PoolConfig(num_pages=args.pool_pages, page_tokens=16,
                    max_pages_per_seq=16),
-        max_active=args.max_active, backend="ref",
-        sleep_us=args.sleep_us)
+        max_active=args.max_active, sleep_us=args.sleep_us)
 
     rng = np.random.default_rng(0)
     t0 = time.time()

@@ -24,7 +24,7 @@ from typing import Any, Dict
 import numpy as np
 
 from ..models.config import ModelConfig, ShapeConfig
-from ..sharding import logical_to_spec
+from ..sharding import auto_axes, logical_to_spec
 
 Params = Any
 
@@ -79,6 +79,7 @@ def sharding_tree(axes_tree, rules, mesh):
 
     from ..models.transformer import is_axes_leaf
 
+    mesh = auto_axes(mesh)
     return jax.tree.map(
         lambda a: NamedSharding(mesh, logical_to_spec(a or (), rules)),
         axes_tree, is_leaf=is_axes_leaf)
@@ -92,6 +93,7 @@ def zero_sharding_tree(param_shapes, axes_tree, rules, mesh):
 
     from ..models.transformer import is_axes_leaf
 
+    mesh = auto_axes(mesh)
     mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh_axes)
     dp_n = int(np.prod([mesh_axes[a] for a in dp_axes])) if dp_axes else 1
@@ -137,8 +139,7 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig, rules, mesh):
     import jax
     from jax.sharding import NamedSharding
 
-    from ..sharding import logical_to_spec
-
+    mesh = auto_axes(mesh)
     B, S = shape.global_batch, shape.seq_len
     i32 = jax.numpy.int32
     structs: Dict[str, Any] = {

@@ -238,6 +238,38 @@ class StreamScheduler:
             return slot
 
     # -- the continuous batch tick --------------------------------------
+    def batch_inputs(self, live: List[_StreamSlot]) -> tuple:
+        """``paged_decode_step``'s arguments after ``cfg, params`` for a
+        step over ``live``.
+
+        The batch is padded to ONE fixed bucket (max_active): the admit/
+        retire schedule is timing-dependent, so stepping at the raw
+        batch size would ask XLA for a fresh compile of
+        paged_decode_step at every new B the ramp happens to hit —
+        seconds of compile against a sub-millisecond step. Padding
+        rows repeat slot 0, so their pool writes land on slot 0's
+        (page, slot) with slot 0's exact values — duplicate but
+        identical, hence benign — and their logits/oob are sliced off."""
+        eng = self.engine
+        B = len(live)
+        MAXP = eng.pool.pc.max_pages_per_seq
+        Bp = max(B, eng.max_active)
+        bt = np.zeros((Bp, MAXP), np.int32)
+        pos = np.zeros((Bp,), np.int32)
+        toks = np.zeros((Bp,), np.int32)
+        for i, s in enumerate(live):
+            bt[i, : len(s.pages)] = s.pages
+            pos[i] = s.pos
+            toks[i] = s.cur
+        if Bp > B:
+            bt[B:] = bt[0]
+            pos[B:] = pos[0]
+            toks[B:] = toks[0]
+        return (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
+                jnp.asarray(pos + 1), eng.pool.k, eng.pool.v,
+                eng.pool.perm_bits(), eng.pool.sandbox_desc(),
+                eng.pool.sandbox_bitmap(eng.conn_id))
+
     def step_batch(self) -> int:
         """One batched decode step over every live, unfinished slot.
         Caller must hold the engine lock. Returns the batch size."""
@@ -247,36 +279,9 @@ class StreamScheduler:
         if not live:
             return 0
         B = len(live)
-        MAXP = eng.pool.pc.max_pages_per_seq
-        # pad the batch to ONE fixed bucket (max_active): the admit/
-        # retire schedule is timing-dependent, so stepping at the raw
-        # batch size would ask XLA for a fresh compile of
-        # paged_decode_step at every new B the ramp happens to hit —
-        # seconds of compile against a sub-millisecond step. Padding
-        # rows repeat slot 0, so their pool writes land on slot 0's
-        # (page, slot) with slot 0's exact values — duplicate but
-        # identical, hence benign — and their logits/oob are sliced off.
-        Bp = max(B, eng.max_active)
-        bt = np.zeros((Bp, MAXP), np.int32)
-        pos = np.zeros((Bp,), np.int32)
-        lens = np.zeros((Bp,), np.int32)
-        toks = np.zeros((Bp,), np.int32)
-        for i, s in enumerate(live):
-            bt[i, : len(s.pages)] = s.pages
-            pos[i] = s.pos
-            lens[i] = s.pos + 1
-            toks[i] = s.cur
-        if Bp > B:
-            bt[B:] = bt[0]
-            pos[B:] = pos[0]
-            lens[B:] = lens[0]
-            toks[B:] = toks[0]
-
         logits, eng.pool.k, eng.pool.v, oob = paged_decode_step(
-            eng.cfg, eng.params, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(bt), jnp.asarray(lens), eng.pool.k, eng.pool.v,
-            eng.pool.perm_bits(), eng.pool.sandbox_desc(),
-            eng.pool.sandbox_bitmap(eng.conn_id), backend=eng.backend)
+            eng.cfg, eng.params, *self.batch_inputs(live),
+            backend=eng.backend)
         eng.decode_steps += 1
         eng.stream_steps += 1
         eng.oob_events += int(jnp.sum(oob[:B]))
@@ -456,7 +461,8 @@ class ServeEngine:
             total = len(req.prompt) + req.max_new
             try:
                 req.pages = self.pool.alloc_seq(total, self.conn_id)
-            except Exception:
+            except (AllocationError, Overloaded):
+                # pool or quota pressure: retry on a later tick
                 self.queue.insert(0, req)
                 break
             try:
@@ -469,7 +475,7 @@ class ServeEngine:
                 req.pos = len(req.prompt)
                 self._pending_attach[req.rid] = req
                 self._handoff(req)        # ← the paper's RPC
-            except Exception:
+            except BaseException as e:
                 # a failed admit must not leak: drop the pending-attach
                 # record, release any flight seals, hand the pages back,
                 # and reset the request so a retry starts clean
@@ -484,6 +490,11 @@ class ServeEngine:
                 req.out = []
                 req.pos = 0
                 self.queue.insert(0, req)
+                # a typed RPC or pool failure is retried on a later tick;
+                # anything else (a compile error, device OOM) is a fault
+                # that retrying cannot fix, so its cause surfaces here
+                if not isinstance(e, (ChannelError, AllocationError)):
+                    raise
                 break
             admitted += 1
         return admitted

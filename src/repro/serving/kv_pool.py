@@ -63,8 +63,9 @@ class PagedKVPool:
         # NOTE: the seal descriptor ring consumed heap pages 0..r-1; those
         # pages exist in the device pool too but are never handed to
         # requests (state == USED, owner 0).
-        self.k = jnp.zeros((L, P, T, Hkv, D), jnp.bfloat16)
-        self.v = jnp.zeros((L, P, T, Hkv, D), jnp.bfloat16)
+        # head-major pages: one KV head of a page is one (T, D) tile
+        self.k = jnp.zeros((L, P, Hkv, T, D), jnp.bfloat16)
+        self.v = jnp.zeros((L, P, Hkv, T, D), jnp.bfloat16)
         self.owner_pid = owner_pid
         self.orch = orch
         self.pod = pod
@@ -176,22 +177,12 @@ class PagedKVPool:
             cache_k = jnp.pad(cache_k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             cache_v = jnp.pad(cache_v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         L = cache_k.shape[0]
-        kb = cache_k.reshape(L, nP, T, *cache_k.shape[2:])
-        vb = cache_v.reshape(L, nP, T, *cache_v.shape[2:])
+        # (L, nP·T, Hkv, D) → (L, nP, Hkv, T, D)
+        kb = cache_k.reshape(L, nP, T, *cache_k.shape[2:]).swapaxes(2, 3)
+        vb = cache_v.reshape(L, nP, T, *cache_v.shape[2:]).swapaxes(2, 3)
         idx = jnp.asarray(pages, jnp.int32)
         self.k = self.k.at[:, idx].set(kb.astype(self.k.dtype))
         self.v = self.v.at[:, idx].set(vb.astype(self.v.dtype))
-
-    def write_token(self, k_new, v_new, block_tab, pos) -> None:
-        """Insert one decoded token's KV. k_new/v_new: (L, B, Hkv, D);
-        block_tab: (B, MAXP) i32; pos: (B,) i32 (the slot being written)."""
-        T = self.pc.page_tokens
-        page = jnp.take_along_axis(
-            block_tab, (pos // T)[:, None], axis=1)[:, 0]      # (B,)
-        slot = pos % T
-        # fancy-index write: (L, B, Hkv, D) lands at [:, page_b, slot_b]
-        self.k = self.k.at[:, page, slot].set(k_new.astype(self.k.dtype))
-        self.v = self.v.at[:, page, slot].set(v_new.astype(self.v.dtype))
 
     def stats(self) -> Dict[str, int]:
         return self.heap.stats()
@@ -200,7 +191,8 @@ class PagedKVPool:
 def transfer_pages_cross_pod(src_pool: "PagedKVPool",
                              dst_pool: "PagedKVPool",
                              src_pages: List[int], dst_pages: List[int],
-                             backend: str = "ref", link=None) -> int:
+                             backend: Optional[str] = None,
+                             link=None) -> int:
     """The RDMA/DCN fallback data plane (§4.7): when prefill and decode
     live in different pods (no shared ICI domain), the block-table RPC
     degrades to gather(src pages) → wire → scatter(dst pages). Returns
@@ -214,11 +206,11 @@ def transfer_pages_cross_pod(src_pool: "PagedKVPool",
 
     from ..kernels.scope_copy.ops import gather_pages, scatter_pages
 
-    L = src_pool.k.shape[0]
+    L, P = src_pool.k.shape[:2]
     sp = jnp.asarray(src_pages, jnp.int32)
     dp = jnp.asarray(dst_pages, jnp.int32)
-    P = src_pool.k.shape[1]
-    flat = lambda a: a.reshape(L * P, -1)
+    # (L, P, Hkv, T, D) → (L·P, Hkv·T, D): one page row per (layer, page)
+    flat = lambda a: a.reshape(L * P, -1, a.shape[-1])
 
     moved = 0
     for name in ("k", "v"):
@@ -266,7 +258,7 @@ class PoolPages:
     __slots__ = ("pool", "pages", "backend", "last_moved_bytes")
 
     def __init__(self, pool: PagedKVPool, pages: List[int],
-                 backend: str = "ref"):
+                 backend: Optional[str] = None):
         self.pool = pool
         self.pages = list(pages)
         self.backend = backend

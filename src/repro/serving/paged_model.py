@@ -31,6 +31,21 @@ def _layer_params(stack: Params, layer: int) -> Params:
     return jax.tree.map(lambda x: x[layer], stack["pos0"])
 
 
+def _write_token(pool, layer: int, page, slot, new):
+    """Write one token per sequence into ``pool[layer]`` (P, Hkv, T, D).
+
+    ``new``: (B, Hkv, D) at ``(page[b], :, slot[b])``. The write goes
+    through whole pages: read each page, set its row, store the page
+    back. A scatter of single rows would make the TPU compiler relayout
+    the whole pool around every layer's write; whole (T, D) tiles keep
+    the pool in the layout the attention kernel reads. Sequences sharing
+    a page (padding rows) store identical pages."""
+    pages = pool[layer, page]                               # (B, Hkv, T, D)
+    b = jnp.arange(page.shape[0])
+    pages = pages.at[b, :, slot].set(new.astype(pool.dtype))
+    return pool.at[layer, page].set(pages)
+
+
 def check_paged_compatible(cfg: ModelConfig) -> None:
     pattern = cfg.block_pattern()
     if len(pattern) != 1 or pattern[0].kind != "attn" or pattern[0].moe:
@@ -47,11 +62,11 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, pos,
 
     tokens: (B,) i32; pos: (B,) i32 (position being generated);
     block_tab: (B, MAXP); seq_lens: (B,) valid length AFTER this token.
-    k_pool/v_pool: (L, P, T, Hkv, D). Returns (logits, k_pool, v_pool,
+    k_pool/v_pool: (L, P, Hkv, T, D). Returns (logits, k_pool, v_pool,
     oob_total) — pools updated with this token's KV.
     """
     spec = cfg.block_pattern()[0]
-    T = k_pool.shape[2]
+    T = k_pool.shape[3]
     B = tokens.shape[0]
 
     x = embed_tokens(tokens[:, None], params["embed"], cfg.embed_scale,
@@ -71,14 +86,12 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, pos,
             k_new = apply_rope(k_new, pos[:, None], spec.rope_theta)
 
         # write this token's KV into its page slot (the pool is the heap)
-        k_pool = k_pool.at[l, page, slot].set(
-            k_new[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[l, page, slot].set(
-            v_new[:, 0].astype(v_pool.dtype))
+        k_pool = _write_token(k_pool, l, page, slot, k_new[:, 0])
+        v_pool = _write_token(v_pool, l, page, slot, v_new[:, 0])
 
         out, oob = paged_attention(
-            q[:, 0], k_pool[l], v_pool[l], block_tab, seq_lens,
-            perm_bits, sandbox, bitmap, backend=backend)
+            q[:, 0], k_pool, v_pool, block_tab, seq_lens,
+            perm_bits, sandbox, bitmap, backend=backend, layer=l)
         oob_total = oob_total + oob
         a = jnp.einsum("bhk,hkd->bd", out, lp["attn"]["wo"])[:, None]
         x = x + a

@@ -38,12 +38,36 @@ def current_mesh():
     return getattr(_state, "mesh", None)
 
 
+def auto_axes(mesh):
+    """``mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes, under which every op must
+    be told its output sharding. The model code here annotates logical
+    axes and leaves the rest to the compiler's sharding propagation,
+    which needs ``Auto`` axes; every mesh the rules are applied on passes
+    through here."""
+    from jax.sharding import AxisType, Mesh
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def shard_map(f, *, mesh, **kw):
+    """``jax.shard_map`` over ``mesh`` with ``Auto`` axes, so it takes
+    arrays that are not yet placed on the mesh."""
+    import jax
+
+    return jax.shard_map(f, mesh=auto_axes(mesh), **kw)
+
+
 @contextlib.contextmanager
 def use_rules(rules: Dict[str, Rule], mesh=None):
     old_r = getattr(_state, "rules", None)
     old_m = getattr(_state, "mesh", None)
     _state.rules = rules
-    _state.mesh = mesh
+    _state.mesh = None if mesh is None else auto_axes(mesh)
     try:
         yield
     finally:

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.sharding import shard_map
 
 Params = Any
 
@@ -47,9 +47,7 @@ def pipeline_apply(fn_stage: Callable, x, stage_params, *, mesh,
 
         n_ticks = n_micro + n_stages - 1
         # carries become pod-varying through ppermute; the shard_map below
-        # runs with the replication/vma check off (check_vma=False — the
-        # compat shim maps it to check_rep on older jax), which works on
-        # every jax version without lax.pcast
+        # runs with the vma check off (check_vma=False), so no lax.pcast
         buf = jnp.zeros((mb,) + x_local.shape[1:], x_local.dtype)
         outs = jnp.zeros_like(micros)
 

@@ -128,7 +128,7 @@ class TestGradCompression:
         out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from repro.sharding import shard_map
         from repro.training.grad_comp import compressed_psum, init_error_state
 
         mesh = jax.make_mesh((8,), ("pod",))
@@ -213,8 +213,7 @@ class TestDryrunReducedMesh:
                 step, in_shardings=(p_sh, None, b_sh)).lower(
                 params_shapes, opt_shapes, structs)
             compiled = lowered.compile()
-        from repro.compat import cost_analysis
-        assert cost_analysis(compiled)["flops"] > 0
+        assert compiled.cost_analysis()["flops"] > 0
         print("DRYRUN_CELL_OK")
         """)
         assert "DRYRUN_CELL_OK" in out

@@ -38,8 +38,8 @@ class TestPagedAttention:
     def _inputs(self, B, Hq, Hkv, D, P, T, MAXP, dtype, seed=0):
         ks = jax.random.split(jax.random.fold_in(KEY, seed), 6)
         q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-        kp = jax.random.normal(ks[1], (P, T, Hkv, D), dtype)
-        vp = jax.random.normal(ks[2], (P, T, Hkv, D), dtype)
+        kp = jax.random.normal(ks[1], (1, P, Hkv, T, D), dtype)
+        vp = jax.random.normal(ks[2], (1, P, Hkv, T, D), dtype)
         bt = jax.random.permutation(ks[3], jnp.arange(P))[: B * MAXP] \
             .reshape(B, MAXP).astype(jnp.int32)
         lens = jax.random.randint(ks[4], (B,), 1, MAXP * T + 1)

@@ -15,7 +15,7 @@ end-to-end ``invoke`` / ``invoke_serialized`` paths.
 Drivers:
 
 * a derandomized ``hypothesis`` strategy when the [test] extra is
-  installed (CI runs it on 3.10 and 3.12);
+  installed (CI installs it);
 * a fixed + seeded-random corpus that ALWAYS runs (the pinned container
   image has no hypothesis).
 

@@ -17,7 +17,7 @@ Two drivers run the same model:
 
 * a ``hypothesis`` rule-based state machine (derandomized, so CI runs
   are deterministic) when hypothesis is installed — CI lists it as a
-  test extra on 3.10 and 3.12;
+  test extra;
 * a seeded ``random`` interleaving driver that ALWAYS runs (the pinned
   container image has no hypothesis) and additionally forces wraparound
   across ≥ 3 full laps of the ring.
@@ -272,7 +272,7 @@ class TestMultiInFlightCallAsync:
 
 # ---------------------------------------------------------------------------
 # driver 2: hypothesis rule-based state machine (runs in CI via the
-# [test] extra on 3.10 and 3.12; derandomized for deterministic runs)
+# [test] extra; derandomized for deterministic runs)
 # ---------------------------------------------------------------------------
 if HAVE_HYPOTHESIS:
 

@@ -254,7 +254,9 @@ class TestFailedAdmitLeak:
         eng.pool.write_prefill = flaky
         try:
             rid = eng.submit([1, 2, 3, 4], max_new=4)
-            assert eng._admit() == 0
+            # an untyped fault is rolled back, then surfaces to the caller
+            with pytest.raises(RuntimeError, match="injected prefill"):
+                eng._admit()
             assert eng.queue                       # requeued, not lost
             assert eng._pending_attach == {}
             assert _alloc_stats(eng.pool) == base
