@@ -42,6 +42,7 @@ from .orchestrator import Orchestrator
 from .sandbox import SandboxManager
 from .scope import Scope, ScopePool, create_scope, implicit_scope
 from .seal import SealManager
+from .. import tracing
 from ..configs.global_config import ReproConfig, global_config
 
 # Lazily-bound marshalling module (core/marshal.py imports this module for
@@ -961,10 +962,11 @@ class Channel:
         if not self._streams:
             return 0
         emitted = 0
-        for st in list(self._streams):
-            emitted += st.pump()
-            if st.done:
-                self._streams.remove(st)
+        with tracing.span("rpcool.pump", streams=len(self._streams)):
+            for st in list(self._streams):
+                emitted += st.pump()
+                if st.done:
+                    self._streams.remove(st)
         return emitted
 
     def _drain(self, conn: Connection) -> int:
@@ -980,7 +982,11 @@ class Channel:
             slot = head % cap
             if words[w0 + slot * _SLOT_WORDS] & _M32 != R_REQ:
                 break
-            self._process(conn, slot)
+            if tracing.on:   # the off path is this one test
+                with tracing.span("rpcool.request", fn=int(ring.fn[slot])):
+                    self._process(conn, slot)
+            else:
+                self._process(conn, slot)
             head += 1
             served += 1
         ring.head = head
@@ -1199,7 +1205,6 @@ class ServerLoop:
         self._versions: List[int] = []
         self._scratch: Optional[np.ndarray] = None
         # stats
-        self.n_sweeps = 0
         self.n_served = 0
         for ch in (channels or []):
             self.attach(ch)
@@ -1240,7 +1245,6 @@ class ServerLoop:
         self._refresh_conns()
         conns = self._conns
         n = len(conns)
-        self.n_sweeps += 1
         if n == 0:
             return 0
         if n == 1:  # common case: skip the gather

@@ -48,6 +48,7 @@ from ..core.router import ClusterRouter
 from ..core.service import method, service
 from ..models.config import ModelConfig
 from ..models.model import build_model
+from ..tracing import span
 from .kv_pool import POOL_RETRY_AFTER_S, PagedKVPool, PoolConfig
 from .paged_model import (
     check_paged_compatible,
@@ -216,19 +217,27 @@ class StreamScheduler:
                 raise
             slot = _StreamSlot(eng._mint_rid(), max_new, pages,
                                eng.stream_steps)
-            try:
-                toks = jnp.asarray(prompt, jnp.int32)[None]
-                logits, k, v = prefill_kv(eng.model, eng.params, toks)
-                eng.pool.write_prefill(k[:, 0], v[:, 0], pages,
-                                       len(prompt))
-                # seal for the flight of the generation: the kernel
-                # verifies the seal on every dereference (Fig. 8 step 4)
-                slot.seal_idxs = eng.pool.seal_seq(
-                    pages, holder=eng.client_pid)
-            except BaseException:
-                eng.pool.free_seq(pages)
-                raise
-            slot.cur = int(jnp.argmax(logits[0]))
+            with span("serve.admit", rid=slot.rid, prompt=len(prompt),
+                      max_new=max_new):
+                try:
+                    toks = jnp.asarray(prompt, jnp.int32)[None]
+                    with span("serve.prefill"):
+                        logits, k, v = prefill_kv(eng.model, eng.params,
+                                                  toks)
+                    with span("serve.pool_write"):
+                        eng.pool.write_prefill(k[:, 0], v[:, 0], pages,
+                                               len(prompt))
+                    # seal for the flight of the generation: the kernel
+                    # verifies the seal on every dereference (Fig. 8
+                    # step 4)
+                    with span("serve.seal"):
+                        slot.seal_idxs = eng.pool.seal_seq(
+                            pages, holder=eng.client_pid)
+                except BaseException:
+                    eng.pool.free_seq(pages)
+                    raise
+                with span("serve.first_token"):
+                    slot.cur = int(jnp.argmax(logits[0]))
             slot.pos = len(prompt)
             slot.produced = 1
             slot.buf.append(slot.cur)   # TTFT = 0 decode steps
@@ -279,21 +288,26 @@ class StreamScheduler:
         if not live:
             return 0
         B = len(live)
-        logits, eng.pool.k, eng.pool.v, oob = paged_decode_step(
-            eng.cfg, eng.params, *self.batch_inputs(live),
-            backend=eng.backend)
-        eng.decode_steps += 1
-        eng.stream_steps += 1
-        eng.oob_events += int(jnp.sum(oob[:B]))
-        if B > eng.peak_stream_batch:
-            eng.peak_stream_batch = B
-
-        nxt = np.asarray(jnp.argmax(logits[:B], -1), np.int32)
-        for i, s in enumerate(live):
-            s.cur = int(nxt[i])
-            s.pos += 1
-            s.produced += 1
-            s.buf.append(s.cur)
+        with span("serve.step", batch=B):
+            with span("serve.step.inputs"):
+                args = self.batch_inputs(live)
+            with span("serve.step.dispatch"):
+                logits, eng.pool.k, eng.pool.v, oob = paged_decode_step(
+                    eng.cfg, eng.params, *args, backend=eng.backend)
+            eng.decode_steps += 1
+            eng.stream_steps += 1
+            # the host blocks here until the device finishes the step
+            with span("serve.step.wait"):
+                eng.oob_events += int(jnp.sum(oob[:B]))
+                nxt = np.asarray(jnp.argmax(logits[:B], -1), np.int32)
+            if B > eng.peak_stream_batch:
+                eng.peak_stream_batch = B
+            with span("serve.step.fanout"):
+                for i, s in enumerate(live):
+                    s.cur = int(nxt[i])
+                    s.pos += 1
+                    s.produced += 1
+                    s.buf.append(s.cur)
         return B
 
     # -- per-stream pull -------------------------------------------------
@@ -322,16 +336,18 @@ class StreamScheduler:
         with eng._decode_lock:
             if slot.released:
                 return
-            slot.released = True
-            if slot in self.slots:
-                self.slots.remove(slot)
-            if slot.seal_idxs:
-                eng.pool.complete_and_release(
-                    slot.seal_idxs, eng.client_pid, batched=True)
-                eng.pool.seals.flush()
-                slot.seal_idxs = []
-            eng.pool.free_seq(slot.pages)
-            slot.pages = []
+            with span("serve.release", rid=slot.rid,
+                      pages=len(slot.pages)):
+                slot.released = True
+                if slot in self.slots:
+                    self.slots.remove(slot)
+                if slot.seal_idxs:
+                    eng.pool.complete_and_release(
+                        slot.seal_idxs, eng.client_pid, batched=True)
+                    eng.pool.seals.flush()
+                    slot.seal_idxs = []
+                eng.pool.free_seq(slot.pages)
+                slot.pages = []
 
 
 class ServeEngine:
