@@ -35,10 +35,15 @@ Layout / tiling:
   oob        (B,) int32 output     — whole array in SMEM
 
 Grid: (B, MAXP). The page axis is innermost so the online-softmax scratch
-(m, l, acc) carries across pages of one sequence in VMEM. Each grid step
-DMAs one (Hkv, T, D) K page + V page into VMEM and runs, per KV head, two
-plain 2-D matmuls: (qpk, D)·(T, D)ᵀ and (qpk, T)·(T, D). m and l are kept
-lane-broadcast over 128 lanes.
+(m, l, acc) carries across pages of one sequence in VMEM. A live grid step
+(``p < ceil(seq_len / T)``) DMAs one (Hkv, T, D) K page + V page into VMEM
+and runs two matmuls batched over the KV heads: (Hkv, qpk, D)·(Hkv, T, D)ᵀ
+and (Hkv, qpk, T)·(Hkv, T, D). m and l are kept lane-broadcast over 128
+lanes. A dead step costs only the grid step itself and its scalar
+sandbox checks: its index map repeats the row's last live page
+(``live_slot``), so the pipeline issues no new K/V DMA and never fetches
+the page a dead slot names, and its body skips the math. A row of length
+0 reads as empty (zeros out, no ``oob``).
 """
 
 from __future__ import annotations
@@ -58,6 +63,15 @@ PERM_SEALED = 1  # bit0 — mirrors repro.core.heap.PERM_SEALED
 _LANES = 128
 
 
+def live_slot(p, seq_len, page_tokens: int):
+    """The block-table slot grid step ``p`` reads K/V from: ``p`` while
+    it is live, else the row's last live slot, and slot 0 for a row of
+    length 0. Consecutive dead steps thus keep one block index, so the
+    pipeline fetches nothing for them."""
+    n_live = (seq_len + page_tokens - 1) // page_tokens
+    return jnp.minimum(p, jnp.maximum(n_live - 1, 0))
+
+
 def _kernel(
     # scalar-prefetch refs (SMEM)
     block_tab_ref, seq_lens_ref, perm_ref, sandbox_ref, bitmap_ref,
@@ -69,7 +83,6 @@ def _kernel(
     m_ref, l_ref, acc_ref,
     *,
     page_tokens: int,
-    num_kv: int,
     head_dim: int,
     max_pages: int,
 ):
@@ -100,44 +113,44 @@ def _kernel(
     # SIGSEGV analogue: count violating dereferences of in-use entries
     oob_ref[b] += jnp.where(in_use & ~valid_page, 1, 0).astype(jnp.int32)
 
-    # token-level validity inside the page
-    tok_pos = p * page_tokens + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_tokens), 1)
-    tok_ok = (tok_pos < seq_len) & valid_page                # (1, T)
     scale = 1.0 / math.sqrt(head_dim)
 
-    # ---- online softmax over this page, one KV head at a time ------------
-    for g in range(num_kv):
-        q = q_ref[g].astype(jnp.float32)                     # (qpk, D)
-        k = k_ref[g].astype(jnp.float32)                     # (T, D)
-        v = v_ref[g].astype(jnp.float32)
+    # ---- online softmax over this page, every KV head at once -----------
+    # A masked page contributes nothing (pexp 0, alpha 1), so skipping
+    # dead and violating pages leaves every output bit as it was.
+    @pl.when(valid_page)
+    def _update():
+        tok_pos = p * page_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, page_tokens), 2)
+        tok_ok = tok_pos < seq_len                           # (1, 1, T)
+        q = q_ref[...].astype(jnp.float32)                   # (Hkv, qpk, D)
+        k = k_ref[...].astype(jnp.float32)                   # (Hkv, T, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (qpk, T)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale      # (Hkv, qpk, T)
         s = jnp.where(tok_ok, s, -jnp.inf)
-
-        m_prev = m_ref[g][:, :1]                             # (qpk, 1)
-        l_prev = l_ref[g][:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard: all -inf rows (nothing valid yet) — keep m at -inf, alpha 1
+        m_prev = m_ref[:, :, :1]                             # (Hkv, qpk, 1)
+        l_prev = l_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # guard: all -inf rows (nothing valid yet) — m stays -inf, alpha 1
         finite = m_new > -jnp.inf
         alpha = jnp.where(m_prev > -jnp.inf, jnp.exp(m_prev - m_new), 0.0)
         alpha = jnp.where(finite, alpha, 1.0)
-        pexp = jnp.where(finite, jnp.exp(s - m_new), 0.0)    # (qpk, T)
-
+        pexp = jnp.where(finite, jnp.exp(s - m_new), 0.0)
         l_new = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        pv = jnp.dot(pexp, v, preferred_element_type=jnp.float32)
-        acc_ref[g] = acc_ref[g] * alpha + pv
-        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        pv = jax.lax.dot_general(
+            pexp, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)              # (Hkv, qpk, D)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(p == max_pages - 1)
     def _finish():
-        for g in range(num_kv):
-            l = l_ref[g][:, :1]
-            safe_l = jnp.where(l > 0, l, 1.0)
-            out_ref[g] = (acc_ref[g] / safe_l).astype(out_ref.dtype)
+        l = l_ref[:, :, :1]
+        safe_l = jnp.where(l > 0, l, 1.0)
+        out_ref[...] = (acc_ref[...] / safe_l).astype(out_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -158,11 +171,11 @@ def paged_attention_pallas(
         return (b, 0, 0, 0)
 
     def kv_map(b, p, block_tab, seq_lens, perm, sandbox, bitmap):
-        page = block_tab[b, p]
+        page = block_tab[b, live_slot(p, seq_lens[b], T)]
         return (layer, jnp.clip(page, 0, P - 1), 0, 0, 0)
 
     kernel = functools.partial(
-        _kernel, page_tokens=T, num_kv=Hkv, head_dim=D, max_pages=MAXP)
+        _kernel, page_tokens=T, head_dim=D, max_pages=MAXP)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,

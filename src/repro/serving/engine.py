@@ -256,9 +256,10 @@ class StreamScheduler:
         batch size would ask XLA for a fresh compile of
         paged_decode_step at every new B the ramp happens to hit —
         seconds of compile against a sub-millisecond step. Padding
-        rows repeat slot 0, so their pool writes land on slot 0's
-        (page, slot) with slot 0's exact values — duplicate but
-        identical, hence benign — and their logits/oob are sliced off."""
+        rows repeat slot 0's token, position and block table with
+        length 0: an empty row, which the kernel walks without a page
+        fetch or its math and which writes nothing to the pool; their
+        logits/oob are sliced off."""
         eng = self.engine
         B = len(live)
         MAXP = eng.pool.pc.max_pages_per_seq
@@ -274,8 +275,10 @@ class StreamScheduler:
             bt[B:] = bt[0]
             pos[B:] = pos[0]
             toks[B:] = toks[0]
+        lens = pos + 1
+        lens[B:] = 0
         return (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
-                jnp.asarray(pos + 1), eng.pool.k, eng.pool.v,
+                jnp.asarray(lens), eng.pool.k, eng.pool.v,
                 eng.pool.perm_bits(), eng.pool.sandbox_desc(),
                 eng.pool.sandbox_bitmap(eng.conn_id))
 
@@ -288,7 +291,10 @@ class StreamScheduler:
         if not live:
             return 0
         B = len(live)
-        with span("serve.step", batch=B):
+        T = eng.pool.pc.page_tokens
+        # block-table slots the kernel reads this step (its live pages)
+        live_pages = sum(s.pos // T + 1 for s in live)
+        with span("serve.step", batch=B, live_pages=live_pages):
             with span("serve.step.inputs"):
                 args = self.batch_inputs(live)
             with span("serve.step.dispatch"):

@@ -38,12 +38,12 @@ def _write_token(pool, layer: int, page, slot, new):
     through whole pages: read each page, set its row, store the page
     back. A scatter of single rows would make the TPU compiler relayout
     the whole pool around every layer's write; whole (T, D) tiles keep
-    the pool in the layout the attention kernel reads. Sequences sharing
-    a page (padding rows) store identical pages."""
+    the pool in the layout the attention kernel reads. A row whose
+    ``page`` lies past the pool writes nothing."""
     pages = pool[layer, page]                               # (B, Hkv, T, D)
     b = jnp.arange(page.shape[0])
     pages = pages.at[b, :, slot].set(new.astype(pool.dtype))
-    return pool.at[layer, page].set(pages)
+    return pool.at[layer, page].set(pages, mode="drop")
 
 
 def check_paged_compatible(cfg: ModelConfig) -> None:
@@ -62,6 +62,8 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, pos,
 
     tokens: (B,) i32; pos: (B,) i32 (position being generated);
     block_tab: (B, MAXP); seq_lens: (B,) valid length AFTER this token.
+    A row with ``seq_lens`` 0 is empty (batch padding): it attends to
+    nothing and writes nothing, whatever its block table holds.
     k_pool/v_pool: (L, P, Hkv, T, D). Returns (logits, k_pool, v_pool,
     oob_total) — pools updated with this token's KV.
     """
@@ -72,6 +74,7 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, pos,
     x = embed_tokens(tokens[:, None], params["embed"], cfg.embed_scale,
                      cfg.d_model)
     page = jnp.take_along_axis(block_tab, (pos // T)[:, None], axis=1)[:, 0]
+    page = jnp.where(seq_lens > 0, page, k_pool.shape[1])   # empty: no write
     slot = pos % T
     oob_total = jnp.zeros((B,), jnp.int32)
 

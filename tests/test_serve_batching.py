@@ -8,6 +8,7 @@ from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
@@ -179,6 +180,63 @@ class TestContinuousBatching:
             assert eng.pool.stats()["sealed_pages"] == 0
         finally:
             eng.shutdown()
+
+
+class TestEmptyPaddingRows:
+    def test_padding_rows_are_empty_and_served_tokens_agree(
+            self, small_lm, monkeypatch):
+        """Three live streams in a batch of 8: rows 3..7 are padding of
+        length 0 (row 0's block table and position, nothing attended,
+        nothing written). The kernel in interpret mode serves exactly
+        the oracle's tokens, and each ``serve.step`` span counts the
+        pages the live rows hold."""
+        from repro.serving import engine as engine_mod
+
+        cfg, m, params = small_lm
+        T = 8
+        prompts = [[5, 6, 7, 8], [9, 10, 11], [2, 3, 4, 5, 6, 7, 8, 9, 10]]
+        served = {}
+        for backend in ("ref", "interpret"):
+            eng = ServeEngine(cfg, params,
+                              PoolConfig(num_pages=64, page_tokens=T,
+                                         max_pages_per_seq=8),
+                              max_active=8, backend=backend)
+            sch = eng.scheduler
+            stats, want = [], []
+            real_span = engine_mod.span
+
+            def recording(name, **kw):
+                if name == "serve.step":
+                    stats.append(kw)
+                    want.append(sum(
+                        -(-(s.pos + 1) // T) for s in sch.slots
+                        if not s.released and s.produced < s.max_new))
+                return real_span(name, **kw)
+
+            monkeypatch.setattr(engine_mod, "span", recording)
+            gens = [eng.generate_tokens(p, 12) for p in prompts]
+            outs = [[next(g)] for g in gens]     # admitted: 3 live rows
+            live = list(sch.slots)
+            _, pos, bt, lens = (np.asarray(a)
+                                for a in sch.batch_inputs(live)[:4])
+            assert lens.shape == (8,)
+            assert lens[:3].tolist() == [s.pos + 1 for s in live]
+            assert (lens[3:] == 0).all()
+            assert (bt[3:] == bt[0]).all() and (pos[3:] == pos[0]).all()
+            for _ in range(11):
+                for g, out in zip(gens, outs):
+                    out.append(next(g))
+            assert all(next(g, None) is None for g in gens)
+            monkeypatch.setattr(engine_mod, "span", real_span)
+            assert [s["batch"] for s in stats] == [3] * 11
+            assert [s["live_pages"] for s in stats] == want
+            assert want[0] == 1 + 1 + 2 and want[-1] == 2 + 2 + 3
+            assert eng.oob_events == 0
+            served[backend] = outs
+        assert served["interpret"] == served["ref"]
+        # a lone stream (seven empty rows) serves the batched tokens too
+        assert [list(eng.generate_tokens(p, 12)) for p in prompts] \
+            == served["interpret"]
 
 
 class TestAttachTable:

@@ -11,6 +11,9 @@ and every test worker imports every test file.
 
 Shapes are the yi-9b ``serve_stage()`` preset: 8 sequences, 32 query / 4
 KV heads of 128, 16-token pages, its pool and its 64-page block tables.
+The attention kernel is also compiled at OLMo-1B's serving widths: 16
+sequences, 16 query and 16 KV heads of 128, 16-token pages, 2,049 pages
+and 128-page block tables.
 """
 
 import os
@@ -20,7 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import yi_9b
+from repro.configs import olmo_1b, yi_9b
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.scope_copy.ops import gather_pages, scatter_pages
 from repro.models import build_model
@@ -68,16 +71,27 @@ def _fits(compiled):
     return ma
 
 
-def test_paged_attention_compiles_at_serve_stage(spec):
+# (B, L, P, T, MAXP, Hq, Hkv, D) as each model is served
+SERVE_SHAPES = {
+    "yi-9b-stage": (B, L, P, T, MAXP, HQ, HKV, D),
+    "olmo-1b": (16, olmo_1b.CONFIG.num_layers, 2049, 16, 128,
+                olmo_1b.CONFIG.num_heads, olmo_1b.CONFIG.num_kv_heads,
+                olmo_1b.CONFIG.head_dim),
+}
+
+
+@pytest.mark.parametrize("model", list(SERVE_SHAPES))
+def test_paged_attention_compiles_at_serve_stage(spec, model):
+    b, l, p, t, maxp, hq, hkv, d = SERVE_SHAPES[model]
     i32 = jnp.int32
-    pool = spec((L, P, HKV, T, D), jnp.bfloat16)
+    pool = spec((l, p, hkv, t, d), jnp.bfloat16)
     compiled = paged_attention.lower(
-        spec((B, HQ, D), jnp.bfloat16), pool, pool, spec((B, MAXP), i32),
-        spec((B,), i32), spec((P,), i32), spec((3,), i32), spec((P,), i32),
-        backend="tpu", layer=L - 1).compile()
+        spec((b, hq, d), jnp.bfloat16), pool, pool, spec((b, maxp), i32),
+        spec((b,), i32), spec((p,), i32), spec((3,), i32), spec((p,), i32),
+        backend="tpu", layer=l - 1).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the layer is read in place: no copy of the pool
-    assert _fits(compiled).temp_size_in_bytes < P * HKV * T * D * 2
+    assert _fits(compiled).temp_size_in_bytes < p * hkv * t * d * 2
 
 
 @pytest.mark.parametrize("kernel", ["gather", "scatter"])
