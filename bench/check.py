@@ -20,7 +20,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from . import spec, traffic, weights
+from . import model, spec, traffic
 
 SAMPLE = 8          # sequences the reference runs, the longest among them
 GROUP = 4           # sequences per reference call
@@ -60,8 +60,8 @@ def gaps(m: Dict, seed: int, picked, length: int) -> np.ndarray:
     """Per served token, the reference's best logit minus its logit."""
     ref = spec.reference(m["reference"])
     toks, tgts = arrays(picked, length)
-    best, tgt, _ = ref.forward(weights.Dims.of(m), m, seed, toks, tgts,
-                               group=GROUP)
+    best, tgt, _ = ref.forward(model.family(m).Dims.of(m), m, seed, toks,
+                               tgts, group=GROUP)
     return (best - tgt)[tgts >= 0]
 
 
@@ -69,7 +69,7 @@ def control_gaps(m: Dict, seed: int, picked, length: int) -> np.ndarray:
     """The control: at each served position, the token the float8
     reference puts first, and the float32 reference's gap for it."""
     ref = spec.reference(m["reference"])
-    dm = weights.Dims.of(m)
+    dm = model.family(m).Dims.of(m)
     toks, tgts = arrays(picked, length)
     _, _, low = ref.forward(dm, m, seed, toks, tgts, quant=True,
                             group=GROUP)

@@ -16,7 +16,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .client import Record
-from .trace import Trace
+from .trace import Span, Trace
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -119,6 +119,14 @@ class Run:
             return []
         return [e for e in self.trace.in_window(self.trace.ops)
                 if any(s in e[0] for s in substrings)]
+
+    def program(self, prefix: str) -> List[Span]:
+        """The program's spans whose name starts with ``prefix``, inside
+        the traced window, with their stats (the program's counters)."""
+        if self.trace is None:
+            return []
+        return [sp for sp in self.trace.in_window(self.trace.program)
+                if sp.name.startswith(prefix)]
 
 
 def seconds_of(events) -> float:

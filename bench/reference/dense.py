@@ -11,8 +11,9 @@ paging or batching of requests, every product at
 
 norm is RMSNorm with a scale (yi) or LayerNorm without scale or bias
 (OLMo). RoPE rotates the two halves of each head (the Llama/HF layout).
-Weights are rebuilt one layer at a time from the seed (``bench.weights``)
-and upcast to float32, so the reference holds nothing the program made.
+Weights are rebuilt one layer at a time from the seed by the dense
+family's builder (``bench/families/dense.py``) and upcast to float32, so
+the reference holds nothing the program made.
 
 ``quant=True`` is the control: the same forward with every weight matrix
 and every activation that enters one rounded to float8 (e4m3, per output
@@ -30,7 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import weights
+from .. import spec
+
+_family = spec.family("dense")
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -118,9 +121,10 @@ def _head(x, outer, targets, *, kind, eps, quant):
     return best, tgt, jnp.argmax(logits, -1).astype(jnp.int32)
 
 
-def forward(dm: weights.Dims, cfg: Dict, seed: int, tokens: np.ndarray,
+def forward(dm, cfg: Dict, seed: int, tokens: np.ndarray,
             targets: np.ndarray, quant: bool = False, group: int = 4):
-    """Run the reference over ``tokens`` (N, S) int32.
+    """Run the reference over ``tokens`` (N, S) int32, with the dense
+    family's ``Dims`` ``dm``.
 
     Returns numpy (best, target_logit, argmax), each (N, S): at position
     ``t`` the logits that predict token ``t + 1``. N must be a multiple
@@ -131,12 +135,12 @@ def forward(dm: weights.Dims, cfg: Dict, seed: int, tokens: np.ndarray,
     kind, eps = cfg["norm_kind"], float(cfg["norm_eps"])
     theta = float(cfg["rope_theta"])
     N = tokens.shape[0]
-    outer = weights.outer(dm, seed)
+    outer = _family.outer(dm, seed)
     emb = outer["embed"]["tok"]
     xs = [jnp.take(emb, jnp.asarray(tokens[i:i + group]), axis=0
                    ).astype(jnp.float32) for i in range(0, N, group)]
     for l in range(dm.layers):
-        w = weights.layer(dm, seed, l)
+        w = _family.layer(dm, seed, l)
         xs = [_layer(x, w, kind=kind, eps=eps, theta=theta, quant=quant)
               for x in xs]
         del w

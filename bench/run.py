@@ -13,10 +13,12 @@ peak memory is read, the engine is freed and a sample of what was served
 is compared with the float32 reference.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics; with
-``--trace 1`` the window runs under the profiler and the line carries
-the per-layer metrics, the device's busy and window seconds and a
-breakdown. The numbers compared for ``correct`` come last, on stderr and
-under ``compared`` in the line.
+``--trace 1`` the window runs under the profiler, with the program's own
+spans on (``bench.trace.capture``), and the line carries the per-layer
+metrics, the device's busy and window seconds and a breakdown whose idle
+gaps are labelled by the program span over most of each
+(``bench.spans``). The numbers compared for ``correct`` come last, on
+stderr and under ``compared`` in the line.
 
 ``--rehearse`` is the CPU rehearsal for the tests: smoke sizes from the
 configuration's ``rehearsal`` group and interpret-mode kernels. Without
@@ -42,7 +44,7 @@ import tempfile  # noqa: E402
 # libtpu writes its logs under /tmp unless told otherwise
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-from . import check, model, serve, spec, traffic, work  # noqa: E402
+from . import check, model, serve, spans, spec, traffic, work  # noqa: E402
 from .client import OpenLoop  # noqa: E402
 from .measure import Run  # noqa: E402
 
@@ -68,45 +70,58 @@ def op_name(hlo: str) -> str:
     return re.sub(r"(\.\d+|\.remat\d*)+$", "", name)
 
 
-def breakdown(run) -> dict:
-    """Top device ops by time, and the longest device-idle gaps labelled
-    by the benchmark's host span that covers most of each."""
+def client_gaps(t) -> list:
+    """The trace's ten longest device-idle gaps, [label, seconds], each
+    labelled by the benchmark's host span that covers most of it."""
     from . import trace as tr
 
-    t = run.trace
-    totals = {}
-    for name, s, e in t.in_window(t.ops):
-        name = op_name(name)
-        totals[name] = totals.get(name, 0.0) + (e - s) / 1e9
-    ops = sorted(totals.items(), key=lambda kv: -kv[1])[:10]
     # the client drains all the time; a dispatch or a retry wait inside
     # a gap says more about it
     rank = {"bench.dispatch": 3, "bench.retry_wait": 2, "bench.drain": 1}
-    spans = [sp for sp in t.spans if sp[0] in rank]
+    client = [sp for sp in t.spans if sp[0] in rank]
     out = []
     for lo, hi in sorted(tr.gaps(t.ops, t.window),
                          key=lambda g: g[0] - g[1])[:10]:
         cover = {}
-        for name, s, e in spans:
+        for name, s, e in client:
             ov = min(e, hi) - max(s, lo)
             if ov > 0:
                 cover[name] = cover.get(name, 0.0) + ov
         label = max(cover, key=lambda n: (rank[n], cover[n])) if cover \
             else "unattributed"
         out.append([label, (hi - lo) / 1e9])
-    return {"device_ops": [[n, v] for n, v in ops], "idle_gaps": out}
+    return out
+
+
+def breakdown(run) -> dict:
+    """Top device ops by time, and the longest device-idle gaps labelled
+    by the program span that covers most of each, else by the client's."""
+    t = run.trace
+    totals = {}
+    for name, s, e in t.in_window(t.ops):
+        name = op_name(name)
+        totals[name] = totals.get(name, 0.0) + (e - s) / 1e9
+    ops = sorted(totals.items(), key=lambda kv: -kv[1])[:10]
+    return {"device_ops": [[n, v] for n, v in ops],
+            "idle_gaps": spans.label_gaps(t, t.program, client_gaps(t))}
 
 
 def main(argv=None) -> int:
+    return execute(argv)[0]
+
+
+def execute(argv=None):
+    """(exit code, the ``Run`` its metrics were read from; None where the
+    run printed no result)."""
     args = parse(argv)
     if args.rehearse and args.trace:
         print("bench: the CPU rehearsal takes no trace", file=sys.stderr)
-        return EXIT_NO_CHIP
+        return EXIT_NO_CHIP, None
     cell = spec.cell(args.workload)
     device, cache, err = serve.start(cell.chips, args.rehearse)
     if err:
         print(f"bench: {err}", file=sys.stderr)
-        return EXIT_NO_CHIP
+        return EXIT_NO_CHIP, None
     import jax
 
     from .clock import CompileClock, GcClock
@@ -200,8 +215,12 @@ def main(argv=None) -> int:
 
         t_load = time.perf_counter()
         run.trace = tr.load(trace_dir)
-        print(f"trace: {len(run.trace.ops)} device ops read in "
+        print(f"trace: {len(run.trace.ops)} device ops, "
+              f"{len(run.trace.program)} program spans read in "
               f"{time.perf_counter() - t_load:.3f}s")
+        print(spans.idle_line(spans.idle_by_span(run.trace,
+                                                 run.trace.program),
+                              run.trace.window_s))
         shutil.rmtree(trace_dir, ignore_errors=True)
         run.peaks = work.peaks(device["kind"])
         device["busy_s"] = run.trace.busy_s()
@@ -232,7 +251,7 @@ def main(argv=None) -> int:
               f"{'ok' if c['ok'] else 'FAIL'}", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result))
-    return 0
+    return 0, run
 
 
 if __name__ == "__main__":

@@ -19,7 +19,7 @@ import sys
 import time
 from typing import Dict, List, Tuple
 
-from . import model, spec, traffic, weights
+from . import model, spec, traffic
 
 CLIENT_PID0 = 40
 
@@ -126,10 +126,11 @@ def prepare(m: Dict, mix: Dict, seed: int, backend: str):
 
     from repro.serving import ServeEngine
 
+    fam = model.family(m)
     t = [time.perf_counter()]
-    params = weights.make(weights.Dims.of(m), seed)
+    params = fam.make(fam.Dims.of(m), seed)
     jax.block_until_ready(params)
-    eng = ServeEngine(model.program_config(m), params, model.pool_config(m),
+    eng = ServeEngine(fam.program_config(m), params, model.pool_config(m),
                       max_active=m["max_active"], backend=backend,
                       serve_threaded=True)
     t.append(time.perf_counter())

@@ -2,7 +2,8 @@
 time they account for.
 
 The serving thread marks its layer boundaries with host spans
-(``repro.tracing``) on the profiler's clock:
+(``repro.tracing``) on the profiler's clock, which ``bench.trace.capture``
+turns on and ``bench.trace.load`` keeps in ``Trace.program``:
 
     rpcool.request, rpcool.pump           RPC ring, seal, sandbox, marshal
     serve.step, serve.step.*              host half of a decode step
@@ -12,43 +13,41 @@ The serving thread marks its layer boundaries with host spans
 Device-idle time (the window less the union of device ops) is put down
 to the innermost program span open over it; a layer's idle time is then
 what falls inside one of its spans and outside any span of another
-layer nested in it. Three numbers read it:
+layer nested in it. Three per-layer metrics read it
+(``bench/metrics/<name>.py`` call ``read``):
 
     rpc_host_ms    RPC layer's idle time per decode step (``serve.step``)
     step_host_ms   decode-step layer's idle time per decode step
     admit_host_ms  admission layer's idle time per admission
                    (``serve.admit``)
 
+A traced run (``bench.run --trace 1``) labels its longest idle gaps with
+``label_gaps`` and prints ``idle_line``.
+
     python3 -m bench.spans --workload <cell> --seed <n> --seconds <s>
 
-runs the cell exactly as ``python3 -m bench.run ... --trace 1`` does,
-with the program's spans on for the traced window, and after the
-harness's own result line prints one more JSON line, ``program_spans``:
-the three numbers, device-idle seconds by innermost span name (``none``
-where no program span is open; they add up to the window's idle time),
-the longest idle gaps labelled by the program span that covers most of
-each (the client's label where none does), the span count, and what a
-span costs on this host.
+runs the cell exactly as ``python3 -m bench.run ... --trace 1`` does, and
+after the harness's own result line prints one more JSON line,
+``program_spans``: the three numbers, device-idle seconds by innermost
+span name (``none`` where no program span is open; they add up to the
+window's idle time), the longest idle gaps labelled by the program span
+that covers most of each (the client's label where none does), the span
+counts, and what a span costs on this host.
 """
 
 from __future__ import annotations
 
 import bisect
-import contextlib
-import glob
 import heapq
 import json
-import os
 import shutil
 import sys
 import tempfile
 import time
-from types import SimpleNamespace
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
-from .trace import Trace, gaps
+from .trace import Span, Trace, gaps
 
-PREFIXES = ("rpcool.", "serve.")
 NONE = "none"
 
 # metric -> (the names of its layer's spans, the span counted per)
@@ -61,35 +60,6 @@ LAYERS = {
                        "serve.seal", "serve.first_token", "serve.release"),
                       "serve.admit"),
 }
-
-
-class Span(NamedTuple):
-    name: str
-    start: float          # ns, on the trace's clock
-    end: float
-    line: str             # host thread: "<plane>#<line index>"
-    stats: Dict[str, object]
-
-
-def load(log_dir: str) -> List[Span]:
-    """Every host event named ``rpcool.*`` or ``serve.*`` in the newest
-    ``.xplane.pb`` under ``log_dir``, with its thread line and stats."""
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    out = []
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        if plane.name.startswith("/device:"):
-            continue
-        for i, line in enumerate(plane.lines):
-            for e in line.events:
-                if e.name.startswith(PREFIXES):
-                    out.append(Span(e.name, e.start_ns, e.end_ns,
-                                    f"{plane.name}#{i}", dict(e.stats)))
-    return out
 
 
 def innermost(spans: List[Span]) -> List[Tuple[float, float, str]]:
@@ -143,9 +113,8 @@ def idle_by_span(trace: Trace, spans: List[Span]) -> Dict[str, float]:
 def in_window(trace: Trace, spans: List[Span],
               name: Optional[str] = None) -> List[Span]:
     """The spans (named ``name``, if given) that lie in the window."""
-    lo, hi = trace.window
-    return [sp for sp in spans if sp.start >= lo and sp.end <= hi
-            and (name is None or sp.name == name)]
+    return [sp for sp in trace.in_window(spans)
+            if name is None or sp.name == name]
 
 
 def host_ms(trace: Trace, spans: List[Span],
@@ -161,6 +130,23 @@ def host_ms(trace: Trace, spans: List[Span],
         out[metric] = 1e3 * sum(idle.get(x, 0.0) for x in names) / n \
             if n else None
     return out
+
+
+def read(run, metric: str) -> Optional[float]:
+    """One of ``LAYERS``' metrics over a run's traced program spans; None
+    without a trace, or where the window holds none of the spans it is
+    counted per."""
+    if run.trace is None:
+        return None
+    return host_ms(run.trace, run.trace.program)[metric]
+
+
+def idle_line(idle: Dict[str, float], window_s: float) -> str:
+    """``idle_by_span``'s seconds, largest first, as one printed line."""
+    by = sorted(idle.items(), key=lambda kv: -kv[1])
+    return ("device idle by program span: "
+            + ", ".join(f"{n} {v:.6f}s" for n, v in by)
+            + f"; total {sum(idle.values()):.6f}s of {window_s:.6f}s")
 
 
 def label_gaps(trace: Trace, spans: List[Span],
@@ -229,48 +215,16 @@ def report(trace: Trace, spans: List[Span], client_gaps: List[List]
 
 
 def main(argv=None) -> int:
-    """``bench.run`` with ``--trace 1`` and the program's spans on in
-    the traced window; then the ``program_spans`` line. The harness's
-    ``trace.capture`` and ``trace.load`` are wrapped for the one run, so
-    the harness itself runs unchanged."""
+    """``bench.run`` with ``--trace 1``; then the ``program_spans`` line."""
     from . import run
-    from . import trace as tr
 
-    held = {}
-    capture, load_trace = tr.capture, tr.load
-
-    @contextlib.contextmanager
-    def capture_with_spans(log_dir):
-        from repro import tracing
-
-        with capture(log_dir):
-            tracing.enable(True)
-            try:
-                yield
-            finally:
-                tracing.enable(False)
-
-    def load_with_spans(log_dir):
-        held["trace"] = load_trace(log_dir)
-        held["spans"] = load(log_dir)
-        return held["trace"]
-
-    tr.capture, tr.load = capture_with_spans, load_with_spans
-    try:
-        rc = run.main(list(sys.argv[1:] if argv is None else argv)
-                      + ["--trace", "1"])
-    finally:
-        tr.capture, tr.load = capture, load_trace
-    if rc or "trace" not in held:
+    rc, held = run.execute(list(sys.argv[1:] if argv is None else argv)
+                           + ["--trace", "1"])
+    if rc or held is None or held.trace is None:
         return rc
-    t, spans = held["trace"], held["spans"]
-    client = run.breakdown(SimpleNamespace(trace=t))["idle_gaps"]
-    rep = report(t, spans, client)
+    t = held.trace
+    rep = report(t, t.program, run.client_gaps(t))
     rep["span_cost_us"] = span_cost_us()
-    by = sorted(rep["idle_by_span"].items(), key=lambda kv: -kv[1])
-    print("device idle by program span: "
-          + ", ".join(f"{n} {v:.6f}s" for n, v in by)
-          + f"; total {rep['idle_s']:.6f}s of {rep['window_s']:.6f}s")
     print(json.dumps({"program_spans": rep}))
     return 0
 

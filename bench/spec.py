@@ -1,20 +1,27 @@
 """Name resolution: every cell, configuration, traffic mix, per-layer
 metric and limit is a file found by its name in ``BENCHMARK.json``.
 
-    bench/configs/<config>.json     sizes, serving pool, reference kind
+    bench/configs/<config>.json     sizes, serving pool, reference kind,
+                                    family (absent: dense)
+    bench/families/<family>.py      the layer stack: published keys, the
+                                    program's config, seeded weights,
+                                    needed decode and prefill work
     bench/reference/<kind>.py       the plain float32 reference forward
     bench/traffic/<traffic>.json    parameters for bench/traffic.py
     bench/metrics/<metric>.py       one reader per per-layer metric
     bench/limits/<cell>.json        the numbers `correct` compares
 
-A later cell or metric adds files; no existing file changes.
+A later cell, metric or configuration, of this layer stack or another,
+adds files; no existing file changes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List
@@ -33,11 +40,13 @@ def benchmark() -> Dict[str, Any]:
 
 
 def load_module(path: Path, name: str):
-    """Import a Python file by path (file names may hold ``-``)."""
+    """Import a Python file by path (file names may hold ``-``), as
+    ``sys.modules[name]``, which a dataclass in it needs."""
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -56,6 +65,18 @@ def limits(cell: str) -> Dict[str, Any]:
 
 def reference(kind: str):
     return importlib.import_module(f"bench.reference.{kind}")
+
+
+def family(name: str):
+    """The layer stack's module, ``bench/families/<name>.py``. One module
+    object per file in a process, so its jitted builders trace once and
+    its ``Dims`` stay one class."""
+    return _family(BENCH_DIR / "families" / f"{name}.py", name)
+
+
+@functools.lru_cache(maxsize=None)
+def _family(path: Path, name: str):
+    return load_module(path, f"bench_family_{name}")
 
 
 def reader(metric: str):

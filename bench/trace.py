@@ -1,14 +1,17 @@
 """The profiler trace of a window, reduced to plain event lists.
 
 ``capture`` runs the window under ``jax.profiler`` with the Python
-tracer off (it would slow every host call); the client marks the window
-with a ``bench.window`` span. ``load`` reads the ``.xplane.pb`` it wrote
-with nothing but JAX and keeps, on one clock:
+tracer off (it would slow every host call) and the program's own spans
+on (``repro.tracing``); the client marks the window with a
+``bench.window`` span. ``load`` reads the ``.xplane.pb`` it wrote with
+nothing but JAX and keeps, on one clock:
 
     ops      device operations (the "XLA Ops" line of each device plane)
     modules  device programs (the "XLA Modules" line): one event per
              execution of a jitted program or eager op
     spans    the benchmark's host spans (``bench.*``)
+    program  the program's host spans (``rpcool.*``, ``serve.*``), each
+             a ``Span`` with its thread line and its stats (counters)
     window   (start, end) of the ``bench.window`` span
 
 Times are nanoseconds.
@@ -20,25 +23,47 @@ import contextlib
 import glob
 import os
 from dataclasses import dataclass, field
-from typing import List, Tuple
+from typing import Dict, List, NamedTuple, Tuple
 
 Event = Tuple[str, float, float]   # (name, start_ns, end_ns)
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# the program's span names (``repro.tracing``); the host planes also hold
+# the runtime's and the compiler's events, which are no layer's
+PROGRAM = ("rpcool.", "serve.")
+
+
+class Span(NamedTuple):
+    """A program span: an ``Event`` with its thread and its stats."""
+    name: str
+    start: float          # ns, on the trace's clock
+    end: float
+    line: str             # host thread: "<plane>#<line index>"
+    stats: Dict[str, object]
 
 
 @contextlib.contextmanager
 def capture(log_dir: str):
+    """Profile the block, with the program's spans on for its length
+    where the program has them."""
     import jax
 
+    try:
+        from repro import tracing
+    except ImportError:
+        tracing = None
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
     jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
+        if tracing is not None:
+            tracing.enable(True)
         yield
     finally:
+        if tracing is not None:
+            tracing.enable(False)
         jax.profiler.stop_trace()
 
 
@@ -47,6 +72,7 @@ class Trace:
     ops: List[Event] = field(default_factory=list)
     modules: List[Event] = field(default_factory=list)
     spans: List[Event] = field(default_factory=list)
+    program: List[Span] = field(default_factory=list)
     window: Tuple[float, float] = (0.0, 0.0)
     devices: int = 0
 
@@ -109,16 +135,19 @@ def load(log_dir: str) -> Trace:
         device = plane.name.startswith("/device:")
         if device and any(line.name == OPS_LINE for line in plane.lines):
             tr.devices += 1
-        for line in plane.lines:
+        for i, line in enumerate(plane.lines):
             if device and line.name in (OPS_LINE, MODULES_LINE):
                 dst = tr.ops if line.name == OPS_LINE else tr.modules
                 dst.extend((e.name, e.start_ns, e.end_ns)
                            for e in line.events)
             elif not device:
                 for e in line.events:
-                    if e.name.startswith("bench."):
-                        if e.name == "bench.window":
-                            tr.window = (e.start_ns, e.end_ns)
-                        else:
-                            tr.spans.append((e.name, e.start_ns, e.end_ns))
+                    if e.name == "bench.window":
+                        tr.window = (e.start_ns, e.end_ns)
+                    elif e.name.startswith("bench."):
+                        tr.spans.append((e.name, e.start_ns, e.end_ns))
+                    elif e.name.startswith(PROGRAM):
+                        tr.program.append(Span(
+                            e.name, e.start_ns, e.end_ns,
+                            f"{plane.name}#{i}", dict(e.stats)))
     return tr

@@ -1,4 +1,9 @@
-"""Work that the algorithm needs, counted from shapes.
+"""Work that the algorithm needs, counted from shapes, where no layer
+stack shapes it: the chip's peaks, the roofline's least time, and the
+per-token K/V, attention and unembedding counts that every family that
+pages its K/V through the one ``paged_attention`` kernel shares. What a
+layer stack needs (its matrices, its experts) each family counts itself
+(``bench/families/<family>.py``: ``decode_work``, ``prefill_flops``).
 
 These count what the computation requires, not what today's program
 does: a decode step needs every weight read once and the live tokens'
@@ -29,24 +34,9 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def layer_matmul_params(m: Dict) -> int:
-    d, H, Hkv = m["hidden_size"], m["num_attention_heads"], \
-        m["num_key_value_heads"]
-    D, F = m["head_dim"], m["intermediate_size"]
-    return d * H * D + 2 * d * Hkv * D + H * D * d + 3 * d * F
-
-
 def unembed_params(m: Dict) -> int:
+    """The unembedding's matrix (the embedding itself when tied)."""
     return m["hidden_size"] * m["vocab_size"]
-
-
-def weight_bytes(m: Dict) -> int:
-    """Bytes a decode step must read: every layer's matrices and norm
-    scales, the unembedding (the embedding itself when tied), the final
-    norm. The embedding rows a batch looks up are negligible."""
-    d, L = m["hidden_size"], m["num_hidden_layers"]
-    norms = 0 if m["norm_kind"] == "layernorm_np" else (2 * L + 1) * d
-    return BYTES * (L * layer_matmul_params(m) + unembed_params(m) + norms)
 
 
 def kv_token_bytes(m: Dict) -> int:
@@ -61,24 +51,6 @@ def attn_flops(m: Dict, live: int) -> int:
         * m["num_hidden_layers"]
 
 
-def decode_token_flops(m: Dict, live: int) -> int:
-    """One sequence's token in a decode step whose KV holds ``live``
-    tokens (this one included)."""
-    mm = m["num_hidden_layers"] * layer_matmul_params(m) + unembed_params(m)
-    return 2 * mm + attn_flops(m, live)
-
-
-def decode_window(m: Dict, steps: int, lives) -> Tuple[float, float]:
-    """(FLOPs, bytes) the decode steps of a window need: ``steps``
-    executions, one token per entry of ``lives`` (its live length).
-    Weights are read once per step; each token reads its live K/V and
-    writes its own."""
-    flops = sum(decode_token_flops(m, n) for n in lives)
-    kv = kv_token_bytes(m)
-    nbytes = steps * weight_bytes(m) + sum(n * kv + kv for n in lives)
-    return float(flops), float(nbytes)
-
-
 def paged_attention(m: Dict, lives) -> Tuple[float, float]:
     """(FLOPs, bytes) the attention kernel needs over live tokens only:
     per layer and token, q.K and p.V, the live K and V read, q read and
@@ -89,17 +61,6 @@ def paged_attention(m: Dict, lives) -> Tuple[float, float]:
     flops = sum(attn_flops(m, n) for n in lives)
     nbytes = sum(n * kv + 2 * BYTES * L * H * D for n in lives)
     return float(flops), float(nbytes)
-
-
-def prefill_flops(m: Dict, prompt: int) -> int:
-    """A prompt's prefill: every matmul over every position, causal
-    attention (position t attends to t + 1 keys), and the unembedding of
-    the last position only."""
-    L = m["num_hidden_layers"]
-    causal = prompt * (prompt + 1) // 2
-    attn = 4 * m["num_attention_heads"] * m["head_dim"] * causal * L
-    return 2 * prompt * L * layer_matmul_params(m) + attn \
-        + 2 * unembed_params(m)
 
 
 def least_seconds(flops: float, nbytes: float,

@@ -84,13 +84,14 @@ def test_trace_metrics_by_hand():
     assert read("batch_occupancy", run) == pytest.approx(2.5)
     lives = [5, 6, 7, 8, 9]
     assert sorted(run.decode_lives()) == lives
-    flops, nbytes = work.decode_window(M, 2, lives)
+    flops, nbytes = spec.family("dense").decode_window(M, 2, lives)
     least = max(flops / 1e6, nbytes / 1e5)
     assert read("decode_mfu", run) == pytest.approx(100 * least / 0.040)
     f, b = work.paged_attention(M, lives)
     assert read("paged_attention_roofline", run) == pytest.approx(
         100 * max(f / 1e6, b / 1e5) / 0.026)
-    pf = work.prefill_flops(M, 4) + work.prefill_flops(M, 6)
+    dense = spec.family("dense")
+    pf = dense.prefill_flops(M, 4) + dense.prefill_flops(M, 6)
     assert read("prefill_mfu", run) == pytest.approx(100 * pf / 1e6 / 0.040)
     # busy: 50-90, 100-110, 300-330 ms = 80 ms of 2 s
     assert read("device_idle_share", run) == pytest.approx(96.0)

@@ -1,12 +1,12 @@
 """The float32 reference agrees with the program's own model, and the
-bench's weights are the tree the program reads, bit for bit per layer."""
+family's weights are the tree the program reads, bit for bit per layer."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import model, spec, weights
+from bench import model, spec
 
 CONFIGS = [c["name"] for c in spec.benchmark()["configs"]]
 
@@ -20,8 +20,9 @@ def test_weights_tree_is_the_programs(name):
     from repro.models import build_model
 
     m = _smoke(name)
-    ours = jax.eval_shape(lambda: weights.make(weights.Dims.of(m), 0))
-    theirs = build_model(model.program_config(m)).param_shapes()
+    fam = model.family(m)
+    ours = jax.eval_shape(lambda: fam.make(fam.Dims.of(m), 0))
+    theirs = build_model(fam.program_config(m)).param_shapes()
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         assert a.shape == b.shape and a.dtype == b.dtype
@@ -30,14 +31,15 @@ def test_weights_tree_is_the_programs(name):
 @pytest.mark.parametrize("name", CONFIGS)
 def test_one_layer_rebuilds_bit_for_bit(name):
     m = _smoke(name)
-    dm = weights.Dims.of(m)
-    full = weights.make(dm, 2**31 + 5)
+    fam = model.family(m)
+    dm = fam.Dims.of(m)
+    full = fam.make(dm, 2**31 + 5)
     for l in range(dm.layers):
-        one = weights.layer(dm, 2**31 + 5, l)
+        one = fam.layer(dm, 2**31 + 5, l)
         stacked = jax.tree.map(lambda a: a[l], full["stack"]["pos0"])
         for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(stacked)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    out = weights.outer(dm, 2**31 + 5)
+    out = fam.outer(dm, 2**31 + 5)
     np.testing.assert_array_equal(np.asarray(out["embed"]["tok"]),
                                   np.asarray(full["embed"]["tok"]))
 
@@ -49,11 +51,12 @@ def test_reference_matches_the_programs_forward(name):
     from repro.models import build_model
 
     m = _smoke(name)
-    dm = weights.Dims.of(m)
+    fam = model.family(m)
+    dm = fam.Dims.of(m)
     seed = 7
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          weights.make(dm, seed))
-    prog = build_model(model.program_config(m))
+                          fam.make(dm, seed))
+    prog = build_model(fam.program_config(m))
     rng = np.random.default_rng(0)
     S = 24
     toks = rng.integers(1, m["vocab_size"], size=(4, S)).astype(np.int32)
@@ -75,7 +78,7 @@ def test_reference_matches_the_programs_forward(name):
 @pytest.mark.parametrize("name", CONFIGS)
 def test_float8_control_departs_from_the_reference(name):
     m = _smoke(name)
-    dm = weights.Dims.of(m)
+    dm = model.family(m).Dims.of(m)
     toks = np.random.default_rng(1).integers(
         1, m["vocab_size"], size=(4, 16)).astype(np.int32)
     tg = np.zeros_like(toks)

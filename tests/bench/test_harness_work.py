@@ -1,8 +1,12 @@
-"""Work counts against hand counts on small shapes, and the peaks table."""
+"""Work counts against hand counts on small shapes, and the peaks table:
+the shared counts (``bench.work``) and the dense family's
+(``bench/families/dense.py``)."""
 
 import pytest
 
-from bench import work
+from bench import spec, work
+
+dense = spec.family("dense")
 
 M = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
      "head_dim": 2, "intermediate_size": 16, "vocab_size": 10,
@@ -11,14 +15,14 @@ M = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
 
 def test_layer_params_by_hand():
     # wq 8*4*2 + wk,wv 2*(8*2*2) + wo 4*2*8 + mlp 3*8*16
-    assert work.layer_matmul_params(M) == 64 + 64 + 64 + 384
+    assert dense.layer_matmul_params(M) == 64 + 64 + 64 + 384
 
 
 def test_weight_bytes_count_norms_and_unembedding():
     per_layer = 576
     norms = (2 * 3 + 1) * 8
-    assert work.weight_bytes(M) == 2 * (3 * per_layer + 80 + norms)
-    assert work.weight_bytes(dict(M, norm_kind="layernorm_np")) == \
+    assert dense.weight_bytes(M) == 2 * (3 * per_layer + 80 + norms)
+    assert dense.weight_bytes(dict(M, norm_kind="layernorm_np")) == \
         2 * (3 * per_layer + 80)
 
 
@@ -29,10 +33,10 @@ def test_kv_and_attention_by_hand():
 
 
 def test_decode_window_by_hand():
-    flops, nbytes = work.decode_window(M, steps=2, lives=[5, 7])
+    flops, nbytes = dense.decode_window(M, steps=2, lives=[5, 7])
     mm = 3 * 576 + 80
     assert flops == 2 * (2 * mm) + 480 + 672
-    assert nbytes == 2 * work.weight_bytes(M) + (5 * 48 + 48) + (7 * 48 + 48)
+    assert nbytes == 2 * dense.weight_bytes(M) + (5 * 48 + 48) + (7 * 48 + 48)
 
 
 def test_paged_attention_by_hand():
@@ -45,7 +49,7 @@ def test_paged_attention_by_hand():
 def test_prefill_by_hand():
     # 3 positions: matmuls 2*3*3*576, causal 6 pairs x 4*4*2 x 3 layers,
     # last-position unembedding 2*80
-    assert work.prefill_flops(M, 3) == 2 * 3 * 3 * 576 + 6 * 32 * 3 + 160
+    assert dense.prefill_flops(M, 3) == 2 * 3 * 3 * 576 + 6 * 32 * 3 + 160
 
 
 def test_least_time_names_its_bound():

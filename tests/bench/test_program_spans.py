@@ -1,7 +1,9 @@
-"""The program's spans (``repro.tracing``) as ``bench.spans`` reads them:
-a smoke-size engine served under the profiler leaves them on its server
-thread, nested as the serving layers nest; and the device-idle time they
-account for, worked out by hand on a trace built here."""
+"""The program's spans (``repro.tracing``) as the harness reads them: a
+smoke-size engine served under ``bench.trace.capture`` leaves them on its
+server thread, nested as the serving layers nest, and ``bench.trace.load``
+keeps them with their stats; the device-idle time they account for, and
+the three metrics that read it, worked out by hand on a trace built
+here."""
 
 from dataclasses import replace
 from types import SimpleNamespace
@@ -10,14 +12,16 @@ import pytest
 
 from bench import run as bench_run
 from bench import spans as sp
-from bench.trace import Trace, capture
+from bench import spec
+from bench.measure import Run
+from bench.trace import Span, Trace, capture, load
 
 MS = 1e6
 
 
 def _marker(log_dir, name):
     """(line, start, end) of the host event ``name``, with lines named as
-    ``bench.spans.load`` names them."""
+    ``bench.trace.load`` names them."""
     import glob
     import os
 
@@ -58,21 +62,23 @@ def test_served_streams_leave_nested_spans_on_the_server_thread(tmp_path):
         stubs = [eng.router.stub(eng.endpoint_name, DecodeService,
                                  pid=30 + i, pod="pod0")
                  for i in range(len(prompts))]
+        # the capture alone turns the spans on, for its length only
         with capture(log_dir):
-            tracing.enable(True)
-            try:
-                with TraceAnnotation("test.clients"):
-                    streams = [s.generate_stream.stream(p, 6, timeout=60.0)
-                               for s, p in zip(stubs, prompts)]
-                    outs = [list(st) for st in streams]
-            finally:
-                tracing.enable(False)
+            assert tracing.on
+            with TraceAnnotation("test.clients"):
+                streams = [s.generate_stream.stream(p, 6, timeout=60.0)
+                           for s, p in zip(stubs, prompts)]
+                outs = [list(st) for st in streams]
+        assert not tracing.on
     finally:
         eng.shutdown()
     assert outs == refs
 
     client_line, lo, hi = _marker(log_dir, "test.clients")
-    spans = [s for s in sp.load(log_dir) if s.start >= lo and s.end <= hi]
+    program = load(log_dir).program
+    assert program and all(isinstance(s, Span) for s in program)
+    assert not any(s.name.startswith("bench.") for s in program)
+    spans = [s for s in program if s.start >= lo and s.end <= hi]
     serve = [s for s in spans if s.name.startswith("serve.")]
     lines = {s.line for s in serve}
     # every serve.* span lies on one thread, the one that pumps streams,
@@ -86,6 +92,8 @@ def test_served_streams_leave_nested_spans_on_the_server_thread(tmp_path):
 
     steps = [s for s in serve if s.name == "serve.step"]
     assert steps and all(1 <= s.stats["batch"] <= 3 for s in steps)
+    # counters ride on the spans as stats: every live row holds a page
+    assert all(s.stats["live_pages"] >= s.stats["batch"] for s in steps)
     for step in steps:
         waits = [s for s in serve if s.name == "serve.step.wait"
                  and step.start <= s.start and s.end <= step.end]
@@ -106,16 +114,17 @@ def test_served_streams_leave_nested_spans_on_the_server_thread(tmp_path):
 
 # -- the reduction, by hand ----------------------------------------------
 def _span(name, a, b, **stats):
-    return sp.Span(name, a * MS, b * MS, "/host:CPU#1", stats)
+    return Span(name, a * MS, b * MS, "/host:CPU#1", stats)
 
 
-def _trace():
+def _trace(program=()):
     # busy 0-10, 30-40, 90-95, 97-100 ms: idle 10-30, 40-90, 95-97
     t = Trace(window=(0.0, 100 * MS), devices=1)
     t.ops = [("fusion", 0, 10 * MS), ("paged_attention", 30 * MS, 40 * MS),
              ("fusion", 90 * MS, 95 * MS), ("copy", 97 * MS, 100 * MS)]
     t.spans = [("bench.drain", 0, 100 * MS),
                ("bench.dispatch", 96 * MS, 97 * MS)]
+    t.program = list(program)
     return t
 
 
@@ -149,6 +158,29 @@ def test_host_ms_per_step_and_per_admission():
                                 "admit_host_ms": 26.0})
 
 
+def _run(trace):
+    return Run(m={}, records=[], t0=0.0, end=0.1, setup_s=1.0, trace=trace)
+
+
+def test_readers_take_the_host_metrics_from_the_traced_program():
+    run = _run(_trace(SPANS))
+    for name, want in (("step_host_ms", 22.0), ("rpc_host_ms", 7.0),
+                       ("admit_host_ms", 26.0)):
+        assert spec.reader(name).read(run) == pytest.approx(want), name
+
+
+def test_run_gives_the_windows_program_spans_with_their_stats():
+    run = _run(_trace(SPANS))
+    steps = run.program("serve.step")
+    # the step after the window is left out; its children count
+    assert [s.name for s in steps] == ["serve.step", "serve.step.dispatch",
+                                       "serve.step.wait", "serve.step.fanout"]
+    assert steps[0].stats == {"batch": 2}
+    assert [s.stats for s in run.program("serve.admit")] == \
+        [{"rid": 4, "prompt": 16, "max_new": 8}]
+    assert _run(None).program("serve.") == []
+
+
 def _labels(gaps):
     return [label for label, _ in gaps], [secs for _, secs in gaps]
 
@@ -163,12 +195,25 @@ def test_gaps_take_the_program_span_over_most_of_them():
     assert labels == ["serve.first_token", "serve.step.wait",
                       "bench.dispatch"]
     assert secs2 == secs
+    # the harness's breakdown labels them so from the trace's own spans
+    traced = bench_run.breakdown(SimpleNamespace(trace=_trace(SPANS)))
+    assert _labels(traced["idle_gaps"]) == (labels, secs)
+
+
+def test_idle_line_adds_up_to_the_windows_idle_time():
+    line = sp.idle_line(sp.idle_by_span(_trace(), SPANS), 0.1)
+    assert line.startswith("device idle by program span: "
+                           "serve.step.wait 0.020000s, none 0.017000s")
+    assert line.endswith("; total 0.072000s of 0.100000s")
 
 
 def test_a_program_without_spans_reads_nothing():
     t = _trace()
     assert sp.host_ms(t, []) == {"rpc_host_ms": None, "step_host_ms": None,
                                  "admit_host_ms": None}
+    for name in sp.LAYERS:
+        assert spec.reader(name).read(_run(t)) is None
+        assert spec.reader(name).read(_run(None)) is None
     assert sp.idle_by_span(t, []) == pytest.approx({"none": 0.072})
     client = bench_run.breakdown(SimpleNamespace(trace=t))["idle_gaps"]
     assert sp.label_gaps(t, [], client) == client
